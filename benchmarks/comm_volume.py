@@ -210,7 +210,6 @@ def _fleet_rows(fast: bool, replicas: int = 2, requests: int = 8,
 
 
 def _gradcomp_rows(fast: bool) -> list:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_mesh_auto
@@ -230,8 +229,8 @@ def _gradcomp_rows(fast: bool) -> list:
         def step(a):
             return gradcomp.compress_step(a, cc, ("data",))
 
-        f = jax.jit(shard_map(step, mesh=mesh, in_specs=P(),
-                              out_specs=P(), check_rep=False))
+        f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P(),
+                                  out_specs=P(), check_vma=False))
         t0 = time.perf_counter()
         sparse, _, stats = f(g)
         jax.block_until_ready(sparse)

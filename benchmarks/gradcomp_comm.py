@@ -24,7 +24,6 @@ from repro.optim import gradcomp
 
 
 def run(verbose: bool = True):
-    from jax.experimental.shard_map import shard_map
     rows = []
     from repro.launch.mesh import make_mesh_auto
     mesh = make_mesh_auto((1,), ("data",))
@@ -39,8 +38,8 @@ def run(verbose: bool = True):
             def step(a):
                 return gradcomp.compress_step(a, cc, ("data",))
 
-            f = jax.jit(shard_map(step, mesh=mesh, in_specs=P(),
-                                  out_specs=P(), check_rep=False))
+            f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P(),
+                                      out_specs=P(), check_vma=False))
             # heavy-tailed synthetic gradient
             g = (rng.standard_t(3, size=n) *
                  (1 + 50 * (rng.random(n) < 0.001))).astype(np.float32)

@@ -14,6 +14,9 @@ def main() -> None:
                     help="fewer Monte Carlo runs")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (comm_volume, engine_throughput, fig1_wor_vs_wr,
                    fig2_rankfreq, fleet_load, gradcomp_comm,
                    ingest_pipeline, psi_calibration, sketch_throughput,

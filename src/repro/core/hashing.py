@@ -52,14 +52,22 @@ def uniform01(keys: jnp.ndarray, salt) -> jnp.ndarray:
     """Uniform(0, 1] float32 from a hash; strictly positive (safe for log)."""
     h = hash_u32(keys, jnp.asarray(salt, jnp.uint32) ^ _EXP_SALT)
     # Use the top 24 bits -> exactly representable in float32; add 2^-25 so the
-    # value is never 0.
-    u = (h >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0**-24)
+    # value is never 0.  The int32 hop is exact (the value is below 2^24) and
+    # lets the cast lower in Mosaic, which has no uint32 -> float32 cast.
+    u = (h >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32) \
+        * jnp.float32(2.0**-24)
     return u + jnp.float32(2.0**-25)
 
 
 def exp1(keys: jnp.ndarray, salt) -> jnp.ndarray:
-    """Per-key Exp[1] variate r_x (the ppswor randomization, Sec. 2.1)."""
-    return -jnp.log(uniform01(keys, salt))
+    """Per-key Exp[1] variate r_x (the ppswor randomization, Sec. 2.1).
+
+    The top bin's midpoint 1 - 2^-25 rounds to 1.0 in float32, whose log is
+    0 and would make v / r_x^{1/p} infinite; that bin gets its midpoint's
+    variate, -log(1 - 2^-25) = 2^-25 in float32.  Every other bin's -log is
+    above 2^-25, so the floor changes nothing else.
+    """
+    return jnp.maximum(-jnp.log(uniform01(keys, salt)), jnp.float32(2.0**-25))
 
 
 def sign_hash(keys: jnp.ndarray, salt) -> jnp.ndarray:

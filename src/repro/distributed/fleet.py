@@ -126,7 +126,7 @@ class FleetConfig(NamedTuple):
     start_timeout: float = 180.0  # spawn + jax import + restore budget
     # env forced into replica processes (spawn inherits os.environ):
     # analytics replicas are host/CPU tier by default
-    child_env: Tuple[Tuple[str, str], ...] = (("JAX_PLATFORM_NAME", "cpu"),)
+    child_env: Tuple[Tuple[str, str], ...] = (("JAX_PLATFORMS", "cpu"),)
     # wire codec for published checkpoints (repro.distributed.codecs):
     # replicas commit ENCODED leaves (CRC over encoded bytes), the
     # coordinator restores+decodes before the merge.  Seed/key leaves stay

@@ -5,8 +5,8 @@ GPU.  TPU adaptation: the gather becomes a one-hot matmul over width blocks:
 
     est_r  =  sum_j  onehot_j(keys) @ table[r, j*WB:(j+1)*WB]^T
 
-The key batch is sample-sized (k or Bk candidates), so the (K,) accumulator
-tile stays in VMEM across the width sweep; the table streams through once.
+Each (K,) accumulator tile stays in VMEM across the width sweep; the batched
+variant tiles the keys too, so its one-hot block fits the chip's VMEM.
 The final median-over-rows is O(R*K) and runs outside the kernel (ops layer).
 
 Batched variant (``countsketch_query_batched``): the grid grows a leading
@@ -28,11 +28,12 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import hashing
 
 from . import tiling
+from .onehot import onehot_dot
 from .tiling import pad_to as _pad_to
 
 
 def _kernel(meta_ref, keys_ref, table_ref, out_ref, *, rows: int, width: int,
-            block_w: int, block_k: int):
+            block_w: int):
     j = pl.program_id(0)
 
     seed = meta_ref[0].astype(jnp.uint32)
@@ -43,20 +44,18 @@ def _kernel(meta_ref, keys_ref, table_ref, out_ref, *, rows: int, width: int,
 
     keys = keys_ref[...].astype(jnp.uint32)  # (1, K)
     col0 = j * block_w
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_k, block_w), 1) + col0
+    # transposed one-hot (WB, K): the table row is the (1, WB) lhs
+    cols = jax.lax.broadcasted_iota(jnp.int32, (block_w, 1), 0) + col0
 
     ests = []
     for r in range(rows):
         salt = hashing.row_salt(seed, jnp.uint32(r))
         bucket = hashing.bucket_hash(keys, salt, width)  # (1, K)
         sign = hashing.sign_hash(keys, salt)             # (1, K)
-        onehot = (bucket.reshape(block_k, 1) == cols).astype(jnp.float32)
-        trow = table_ref[r, :].reshape(block_w, 1).astype(jnp.float32)
-        part = jax.lax.dot_general(
-            onehot, trow, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (K, 1)
-        ests.append((part.reshape(1, block_k)) * sign)
+        onehot = (bucket == cols).astype(jnp.float32)    # (WB, K)
+        trow = table_ref[r:r + 1, :].astype(jnp.float32)  # (1, WB)
+        part = onehot_dot(trow, onehot, (((1,), (0,)), ((), ())))  # (1, K)
+        ests.append(part * sign)
     out_ref[...] += jnp.concatenate(ests, axis=0)  # (rows, K)
 
 
@@ -81,8 +80,7 @@ def countsketch_query(
     meta = jnp.array([jnp.uint32(seed).astype(jnp.int32)], jnp.int32)
     grid = (w_pad // block_w,)
     out = pl.pallas_call(
-        functools.partial(_kernel, rows=rows, width=width, block_w=block_w,
-                          block_k=k_pad),
+        functools.partial(_kernel, rows=rows, width=width, block_w=block_w),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -114,10 +112,10 @@ _META_COLS = 128
 
 
 def _batched_kernel(meta_ref, keys_ref, table_ref, out_ref, *, rows: int,
-                    width: int, block_w: int, block_k: int):
-    # grid = (batch_blocks, width_blocks): each (stream-block, key-tile)
-    # accumulator revisits across the width sweep; tables stream through once.
-    j = pl.program_id(1)
+                    width: int, block_w: int):
+    # grid = (batch_blocks, key_blocks, width_blocks): each (stream-block,
+    # key-tile) accumulator revisits across the width sweep.
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -126,21 +124,20 @@ def _batched_kernel(meta_ref, keys_ref, table_ref, out_ref, *, rows: int,
     seed = meta_ref[:, _META_SEED:_META_SEED + 1].astype(jnp.uint32)  # (B,1)
     keys = keys_ref[...].astype(jnp.uint32)                           # (B,K)
     col0 = j * block_w
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_k, block_w), 1) + col0
+    # transposed one-hot (B, WB, K): the table row is the (B, 1, WB) lhs, so
+    # every operand keeps its lane dimension and Mosaic needs no gather
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, block_w, 1), 1) + col0
 
     ests = []
     for r in range(rows):
         salt = hashing.row_salt(seed, jnp.uint32(r))          # (B, 1)
         bucket = hashing.bucket_hash(keys, salt, width)       # (B, K)
         sign = hashing.sign_hash(keys, salt)                  # (B, K)
-        onehot = (bucket[:, :, None] == cols[None]).astype(jnp.float32)
-        trow = table_ref[:, r, :][:, :, None].astype(jnp.float32)  # (B,WB,1)
-        part = jax.lax.dot_general(
-            onehot, trow,  # batched contraction: B streams on the MXU
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # (B, K, 1)
-        ests.append(part[:, None, :, 0] * sign[:, None, :])   # (B, 1, K)
+        onehot = (bucket[:, None, :] == cols).astype(jnp.float32)
+        trow = table_ref[:, r:r + 1, :].astype(jnp.float32)   # (B, 1, WB)
+        # batched contraction: B streams on the MXU -> (B, 1, K)
+        part = onehot_dot(trow, onehot, (((2,), (1,)), ((0,), (0,))))
+        ests.append(part * sign[:, None, :])
     out_ref[...] += jnp.concatenate(ests, axis=1)             # (B, rows, K)
 
 
@@ -163,7 +160,7 @@ def countsketch_query_batched(
     """
     B, rows, width = tables.shape
     k = keys.shape[1]
-    k_pad = _pad_to(max(k, tiling.LANE), tiling.LANE)
+    block_k, k_pad = tiling.fit_block(tiling.BLOCK_K, max(k, 1))
     block_w, w_pad = tiling.fit_block(block_w, width)
     block_b, b_pad = tiling.fit_block(block_b, B, tile=tiling.SUBLANE)
 
@@ -174,17 +171,18 @@ def countsketch_query_batched(
     meta = jnp.zeros((b_pad, _META_COLS), jnp.int32)
     meta = meta.at[:B, _META_SEED].set(seeds.astype(jnp.int32))
 
-    grid = (b_pad // block_b, w_pad // block_w)
+    grid = (b_pad // block_b, k_pad // block_k, w_pad // block_w)
     out = pl.pallas_call(
         functools.partial(_batched_kernel, rows=rows, width=width,
-                          block_w=block_w, block_k=k_pad),
+                          block_w=block_w),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b, _META_COLS), lambda b, j: (b, 0)),
-            pl.BlockSpec((block_b, k_pad), lambda b, j: (b, 0)),
-            pl.BlockSpec((block_b, rows, block_w), lambda b, j: (b, 0, j)),
+            pl.BlockSpec((block_b, _META_COLS), lambda b, q, j: (b, 0)),
+            pl.BlockSpec((block_b, block_k), lambda b, q, j: (b, q)),
+            pl.BlockSpec((block_b, rows, block_w), lambda b, q, j: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((block_b, rows, k_pad), lambda b, j: (b, 0, 0)),
+        out_specs=pl.BlockSpec((block_b, rows, block_k),
+                               lambda b, q, j: (b, 0, q)),
         out_shape=jax.ShapeDtypeStruct((b_pad, rows, k_pad), jnp.float32),
         interpret=interpret,
         name="worp_countsketch_query_batched",

@@ -43,6 +43,7 @@ from repro.core import hashing
 # desynchronize (the scatter kernel simply never reads _META_BASE); the
 # block/padding arithmetic is the library-wide tiling helper.
 from . import tiling
+from .onehot import onehot_dot
 from .countsketch_update import (
     _META_COLS,
     _META_N,
@@ -94,13 +95,8 @@ def _batched_kernel(meta_ref, keys_ref, vals_ref, table_ref, *, rows: int,
         sign = hashing.sign_hash(keys, salt)                  # (B, N)
         sv = (sign * vals)[:, None, :]                        # (B, 1, N)
         onehot = (bucket[:, :, None] == cols[None]).astype(jnp.float32)
-        contribs.append(
-            jax.lax.dot_general(
-                sv, onehot,  # batched contraction: B streams on the MXU
-                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )  # (B, 1, WB)
-        )
+        # batched contraction: B streams on the MXU -> (B, 1, WB)
+        contribs.append(onehot_dot(sv, onehot, (((2,), (1,)), ((0,), (0,)))))
     table_ref[...] += jnp.concatenate(contribs, axis=1)  # (B, rows, WB)
 
 

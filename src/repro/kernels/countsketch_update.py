@@ -45,6 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import hashing, transforms
 
 from . import tiling
+from .onehot import onehot_dot
 
 
 def _kernel(meta_ref, vals_ref, table_ref, *, rows: int, width: int,
@@ -84,13 +85,7 @@ def _kernel(meta_ref, vals_ref, table_ref, *, rows: int, width: int,
         sign = hashing.sign_hash(keys, salt)                  # (1, B)
         sv = (sign * vals)                                    # (1, B)
         onehot = (bucket.reshape(block_n, 1) == cols).astype(jnp.float32)
-        contribs.append(
-            jax.lax.dot_general(
-                sv, onehot,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (1, WB)
-        )
+        contribs.append(onehot_dot(sv, onehot, (((1,), (0,)), ((), ()))))
     table_ref[...] += jnp.concatenate(contribs, axis=0)  # (rows, WB)
 
 
@@ -222,13 +217,8 @@ def _batched_kernel(meta_ref, vals_ref, table_ref, *, rows: int, width: int,
         sign = hashing.sign_hash(keys, salt)                  # (B, N)
         sv = (sign * vals)[:, None, :]                        # (B, 1, N)
         onehot = (bucket[:, :, None] == cols[None]).astype(jnp.float32)
-        contribs.append(
-            jax.lax.dot_general(
-                sv, onehot,  # batched contraction: B streams on the MXU
-                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )  # (B, 1, WB)
-        )
+        # batched contraction: B streams on the MXU -> (B, 1, WB)
+        contribs.append(onehot_dot(sv, onehot, (((2,), (1,)), ((0,), (0,)))))
     table_ref[...] += jnp.concatenate(contribs, axis=1)  # (B, rows, WB)
 
 

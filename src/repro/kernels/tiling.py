@@ -24,6 +24,9 @@ SUBLANE = 8
 BLOCK_N = 512
 BLOCK_W = 1024
 BLOCK_B = 8
+# key tile of the batched query: a one-hot over all keys ran a v5e out of
+# VMEM already at 1024 keys ((8, 1024, 1024) f32, 32 MiB).
+BLOCK_K = 512
 
 # single-stream kernels have no batch dimension competing for VMEM, so they
 # afford larger tiles.

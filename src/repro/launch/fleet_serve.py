@@ -37,6 +37,7 @@ from repro.data.pipeline import TurnstileZipfStream
 from repro.distributed import codecs as wire_codecs
 from repro.distributed import fleet as F
 from repro.engine import EngineConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def traffic(stream: TurnstileZipfStream, requests: int, steps: int,
@@ -95,6 +96,7 @@ def main():
                          "(seed/key leaves stay lossless; 'none' keeps "
                          "the bitwise fp32 path)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.replicas < 1:
         ap.error("--replicas must be >= 1")
     if args.kill_replica >= args.replicas:

@@ -9,19 +9,13 @@ device state (the dry-run must set XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.37; default axis types are Auto there
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh_auto(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where the API exists."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types (bare ``make_mesh`` makes them
+    Explicit, which the sharding-in-types rules then enforce)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

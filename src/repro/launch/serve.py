@@ -49,6 +49,7 @@ from repro.core import sampler as core_sampler
 from repro.distributed import codecs as wire_codecs
 from repro.distributed import sharding as shd
 from repro.engine import EngineConfig, SketchEngine, available_planes
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models import transformer as T
 
@@ -139,6 +140,7 @@ def main():
                          "the pipeline collapse encode through it; 'none' "
                          "keeps the bitwise fp32 path")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.worp_topk < 0:
         ap.error("--worp-topk must be >= 0")
     if args.worp_topk and args.worp_p <= 0:

@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh_auto
 
 
 class StragglerWatchdog:
@@ -66,9 +67,9 @@ def plan_remesh(num_devices: int, model_parallel: int, pods: int = 1):
     per_pod = num_devices // pods
     data = per_pod // model_parallel
     if pods > 1:
-        return jax.make_mesh((pods, data, model_parallel),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+        return make_mesh_auto((pods, data, model_parallel),
+                              ("pod", "data", "model"))
+    return make_mesh_auto((data, model_parallel), ("data", "model"))
 
 
 def reshard_tree(tree, mesh, pspecs):

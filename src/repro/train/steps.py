@@ -68,8 +68,6 @@ def make_compressed_train_step(cfg: ArchConfig, mesh,
     batch is sharded.  The only gradient collective is the sketch psum (+ the
     2k-float pass-II all-reduce), instead of an N-float dense all-reduce.
     """
-    from jax.experimental.shard_map import shard_map
-
     def local_step(params, opt, error, batch):
         def loss_fn(p):
             return M.train_loss(p, batch, cfg)
@@ -82,11 +80,11 @@ def make_compressed_train_step(cfg: ArchConfig, mesh,
 
     rep = P()
     batch_spec = {"tokens": P(dp_axes), "labels": P(dp_axes)}
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),
         out_specs=(rep, rep, rep, rep),
-        check_rep=False,
+        check_vma=False,
     )
 
     def step(state: CompressedTrainState, batch):

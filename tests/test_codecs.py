@@ -310,8 +310,6 @@ class TestTable3CodecFloor:
 
 class TestGradcompCodecs:
     def _run(self, codec):
-        from jax.experimental.shard_map import shard_map
-
         from repro.launch.mesh import make_mesh_auto
         from repro.optim import gradcomp
 
@@ -323,9 +321,9 @@ class TestGradcompCodecs:
                         .normal(size=4096).astype(np.float32))
         # planted heavy hitters: selection is then stable across codecs
         a = a.at[:16].set(jnp.arange(16, dtype=jnp.float32) * 50 + 100)
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda x: gradcomp.compress_step(x, cc, ("data",)),
-            mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False))
+            mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
         sparse, err, stats = f(a)
         return np.asarray(sparse), np.asarray(err), stats, cc
 

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 from tests._hypothesis_compat import given, settings, st
 
-from repro.core import counters, countsketch, hashing
+from repro.core import counters, countsketch, hashing, transforms
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -39,6 +39,16 @@ class TestHashing:
         e = np.asarray(hashing.exp1(jnp.arange(200_000), 5))
         assert abs(e.mean() - 1.0) < 0.02
         assert abs(e.var() - 1.0) < 0.05
+
+    def test_exp1_top_bin_is_positive(self):
+        """Key 1487 under salt 2308002755 lands in uniform01's top bin,
+        which rounds to 1.0; its variate must stay positive so the ppswor
+        transform stays finite."""
+        key, salt = jnp.uint32(1487), jnp.uint32(2308002755)
+        assert float(hashing.uniform01(key, salt)) == 1.0
+        assert float(hashing.exp1(key, salt)) == 2.0**-25
+        assert np.isfinite(float(transforms.transform_values(
+            key, jnp.float32(1.0), 1.0, salt)))
 
     def test_sign_hash_balanced(self):
         s = np.asarray(hashing.sign_hash(jnp.arange(100_000), 11))

@@ -12,6 +12,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh_auto
 from repro.train import checkpoint
 from repro.train.elastic import StragglerWatchdog
 
@@ -235,10 +236,9 @@ class TestGradComp:
     def test_compression_invariants_single_worker(self):
         """With one worker + twopass: sampled ids carry exact values and
         error feedback holds exactly the untransmitted residual."""
-        from jax.experimental.shard_map import shard_map
         from repro.optim import gradcomp
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh_auto((1,), ("data",))
         cc = gradcomp.CompressorConfig(k=32, rows=5, width=512,
                                        candidates=64, p=1.0, mode="twopass")
         a = jnp.asarray(
@@ -248,8 +248,8 @@ class TestGradComp:
         def f(x):
             return gradcomp.compress_step(x, cc, ("data",))
 
-        sparse, err, stats = shard_map(
-            f, mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)(a)
+        sparse, err, stats = jax.shard_map(
+            f, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)(a)
         nz = np.nonzero(np.asarray(sparse))[0]
         assert len(nz) == cc.k
         # twopass: exact values at the sampled coordinates

@@ -16,6 +16,7 @@ from repro import engine as E
 from repro.core import countsketch, transforms, worp
 from repro.core import sampler as core_sampler
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh_auto
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -244,12 +245,12 @@ class TestMergeTrees:
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core import worp
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh_auto
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh_auto((4,), ("data",))
 rng = np.random.default_rng(0)
 keys = jnp.asarray(rng.integers(0, 3000, (4, 200)), jnp.int32)
 vals = jnp.asarray(rng.normal(size=(4, 200)).astype(np.float32))
@@ -260,8 +261,8 @@ def worker(k, v):
     g = shd.butterfly_allmerge(st, "data", worp.onepass_merge, axis_size=4)
     return jax.tree_util.tree_map(lambda x: x[None], g)
 
-out = shard_map(worker, mesh=mesh, in_specs=(P("data"), P("data")),
-                out_specs=P("data"), check_rep=False)(keys, vals)
+out = jax.shard_map(worker, mesh=mesh, in_specs=(P("data"), P("data")),
+                    out_specs=P("data"), check_vma=False)(keys, vals)
 sts = []
 for b in range(4):
     st = worp.onepass_init(5, 256, 64, 3, 77)
@@ -322,10 +323,9 @@ print("BUTTERFLY_OK")
             shd.butterfly_allmerge(sts, None, worp.onepass_merge)
 
     def test_psum_sketch_single_device(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh_auto((1,), ("data",))
         sk = countsketch.update(countsketch.init(3, 64, 9), jnp.arange(10),
                                 jnp.ones(10))
 
@@ -335,8 +335,8 @@ print("BUTTERFLY_OK")
                 ("data",))
             return merged.table
 
-        out = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                        check_rep=False)(sk.table)
+        out = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                            check_vma=False)(sk.table)
         np.testing.assert_allclose(np.asarray(out), np.asarray(sk.table))
 
 
@@ -344,11 +344,10 @@ class TestEngineGradComp:
     def test_per_layer_invariants_single_worker(self):
         """Engine path: each layer gets its own exact-valued WOR sample and
         error feedback holds exactly the untransmitted residual."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim import gradcomp
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh_auto((1,), ("data",))
         cc = gradcomp.CompressorConfig(k=32, rows=5, width=512, p=1.0,
                                        mode="twopass")
         rng = np.random.default_rng(0)
@@ -362,9 +361,9 @@ class TestEngineGradComp:
             return gradcomp.tree_compress_step_engine(g, e, cc, ("data",),
                                                       k_per_leaf=16)
 
-        sparse, new_err, stats = shard_map(
+        sparse, new_err, stats = jax.shard_map(
             f, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-            check_rep=False)(grads, err)
+            check_vma=False)(grads, err)
         for name in grads:
             s = np.asarray(sparse[name]).ravel()
             a = np.asarray(grads[name]).ravel()
@@ -379,11 +378,10 @@ class TestEngineGradComp:
     def test_small_leaf_regression(self):
         """A leaf smaller than k_per_leaf (bias/LayerNorm scale) must not
         crash the per-layer path or corrupt other leaves."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim import gradcomp
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh_auto((1,), ("data",))
         cc = gradcomp.CompressorConfig(k=32, rows=3, width=256, p=1.0,
                                        mode="twopass")
         rng = np.random.default_rng(1)
@@ -398,9 +396,9 @@ class TestEngineGradComp:
                                                       k_per_leaf=32,
                                                       cand_per_leaf=64)
 
-        sparse, new_err, _ = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                                       out_specs=P(), check_rep=False)(
-                                           grads, err)
+        sparse, new_err, _ = jax.shard_map(
+            f, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+            check_vma=False)(grads, err)
         for name in grads:
             s = np.asarray(sparse[name]).ravel()
             a = np.asarray(grads[name]).ravel()

@@ -217,6 +217,21 @@ class TestBatchedKernelEdgeCases:
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
+    def test_query_multiple_key_blocks(self):
+        """More keys than one key tile: the batched query's key grid axis."""
+        from repro.kernels import tiling
+        from repro.kernels.countsketch_query import countsketch_query_batched
+
+        rng = np.random.default_rng(4)
+        k = 2 * tiling.BLOCK_K + 77
+        tables = jnp.asarray(rng.normal(size=(3, 2, 300)).astype(np.float32))
+        keys = jnp.asarray(rng.integers(0, 99_999, (3, k)), jnp.int32)
+        seeds = jnp.asarray(rng.integers(0, 2**31 - 1, 3), jnp.uint32)
+        out = countsketch_query_batched(tables, keys, seeds, block_w=128,
+                                        interpret=True)
+        want = ref.countsketch_query_batched_ref(tables, keys, seeds)
+        assert np.array_equal(np.asarray(out), np.asarray(want))
+
     def test_query_single_key(self):
         """k == 1 sample queries (the smallest possible key batch)."""
         from repro.kernels.countsketch_query import countsketch_query_batched

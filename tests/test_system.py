@@ -129,3 +129,46 @@ class TestTVSampler:
             hits_tv += int(0 in sel.tolist())
         # perfect inclusion prob of key 0 is high (~0.7+); allow slack
         assert hits_tv >= trials // 2
+
+
+class TestCompileCache:
+    def test_env_directory_is_used(self, tmp_path):
+        """With JAX_COMPILATION_CACHE_DIR set, compiled programs land there
+        and the helper sets no other directory."""
+        import os
+        import subprocess
+        import sys
+
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                   PYTHONPATH=os.pathsep.join(
+                       ["src", os.environ.get("PYTHONPATH", "")]))
+        script = (
+            "import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "jax.jit(lambda x: x * 2 + 1)(1.0).block_until_ready()\n")
+        out = subprocess.run([sys.executable, "-c", script], env=env,
+                             capture_output=True, text=True, timeout=120,
+                             cwd=os.path.dirname(os.path.dirname(__file__)))
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.split() == [str(tmp_path)] * 2
+        assert any(tmp_path.iterdir())
+
+    def test_default_is_repo_root(self, monkeypatch):
+        """Unset, the cache is .jax_cache/ at the repository root."""
+        import pathlib
+
+        from repro.launch.compile_cache import enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            got = enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert pathlib.Path(got) == root / ".jax_cache"

@@ -1,0 +1,99 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e.
+
+No chip is needed: the TPU compiler is installed and compiles for a
+topology that is described, not attached.  What it refuses here (a cast
+Mosaic cannot lower, a gather it does not support, a block that does not
+fit VMEM) the chip would refuse too, and interpret mode never sees.  Each
+test asserts that a Mosaic kernel (``tpu_custom_call``) is in the compiled
+program, so that neither interpret mode nor the jnp oracle was compiled.
+
+Shapes are a deployment's: width 31 * 1024 (the paper's k x 31 for
+k = 1024), 5 rows, one 8-stream block, 4096 events per stream.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.countsketch_query import countsketch_query_batched
+from repro.kernels.countsketch_scatter import countsketch_scatter_batched
+from repro.kernels.countsketch_update import countsketch_update_batched
+from repro.kernels.ppswor_transform import ppswor_transform
+
+WIDTH, ROWS, STREAMS, EVENTS = 31 * 1024, 5, 8, 4096
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2, with the persistent compile cache
+    off: entries written for a described chip cannot be read back here."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _assert_mosaic(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+_STREAM_PARAMS = (((STREAMS,), jnp.uint32), ((STREAMS,), jnp.uint32),
+                  ((STREAMS,), jnp.int32))
+
+
+@pytest.mark.parametrize("p,scheme", [(1.0, "ppswor"), (2.0, "priority")])
+def test_scatter_batched_compiles(one_chip, p, scheme):
+    def scatter(keys, vals, seeds, tseeds, lengths):
+        return countsketch_scatter_batched(
+            keys, vals, ROWS, WIDTH, seeds, p=p, scheme=scheme,
+            transform_seeds=tseeds, lengths=lengths, interpret=False)
+
+    _assert_mosaic(scatter, one_chip, ((STREAMS, EVENTS), jnp.int32),
+                   ((STREAMS, EVENTS), jnp.float32), *_STREAM_PARAMS)
+
+
+def test_update_batched_compiles(one_chip):
+    def update(vals, seeds, tseeds, lengths, base_keys):
+        return countsketch_update_batched(
+            vals, ROWS, WIDTH, seeds, p=1.0, transform_seeds=tseeds,
+            base_keys=base_keys, lengths=lengths, interpret=False)
+
+    _assert_mosaic(update, one_chip, ((STREAMS, EVENTS), jnp.float32),
+                   *_STREAM_PARAMS, ((STREAMS,), jnp.uint32))
+
+
+# 1024: a k = 1024 sample's keys; 8192: a candidate refresh (4k candidates
+# plus a 4096-event block)
+@pytest.mark.parametrize("keys", [1024, 8192])
+def test_query_batched_compiles(one_chip, keys):
+    def query(tables, qkeys, seeds):
+        return countsketch_query_batched(tables, qkeys, seeds,
+                                         interpret=False)
+
+    _assert_mosaic(query, one_chip, ((STREAMS, ROWS, WIDTH), jnp.float32),
+                   ((STREAMS, keys), jnp.int32), ((STREAMS,), jnp.uint32))
+
+
+def test_transform_compiles(one_chip):
+    def transform(keys, vals):
+        return ppswor_transform(keys, vals, 1.0, 7, interpret=False)
+
+    n = STREAMS * EVENTS
+    _assert_mosaic(transform, one_chip, ((n,), jnp.int32),
+                   ((n,), jnp.float32))
