@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import countsketch, hashing, transforms, worp
 from repro.core import sampler as core_sampler
 from repro.core.perfect import Sample
@@ -415,14 +416,15 @@ class SketchEngine:
         Ingesting a batch and later its negation returns the sketch exactly
         to zero (linearity).
         """
-        keys = np.asarray(keys, np.int32)
-        values = np.asarray(values, np.float32)
-        if keys.shape != values.shape or keys.ndim != 2 \
-                or keys.shape[0] != self.cfg.num_streams:
-            raise ValueError(
-                f"ingest: keys/values must both be (num_streams={self.cfg.num_streams}, n), "
-                f"got {keys.shape} / {values.shape}")
-        self._plane.ingest(keys, values)
+        with obs.span("engine.ingest"):
+            keys = np.asarray(keys, np.int32)
+            values = np.asarray(values, np.float32)
+            if keys.shape != values.shape or keys.ndim != 2 \
+                    or keys.shape[0] != self.cfg.num_streams:
+                raise ValueError(
+                    f"ingest: keys/values must both be (num_streams={self.cfg.num_streams}, n), "
+                    f"got {keys.shape} / {values.shape}")
+            self._plane.ingest(keys, values)
         return self
 
     @property
@@ -433,7 +435,8 @@ class SketchEngine:
     def flush(self, interpret=None, use_kernel=None):
         """Drain the data plane: flush buffered turnstile microbatches and
         settle any in-flight async dispatches; no-op when nothing pends."""
-        self._plane.drain(interpret=interpret, use_kernel=use_kernel)
+        with obs.span("engine.flush"):
+            self._plane.drain(interpret=interpret, use_kernel=use_kernel)
         return self
 
     def update_dense(self, values, base_keys=None, lengths=None,

@@ -72,13 +72,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import countsketch, hashing, tv_sampler, worp
 from repro.core import sampler as core_sampler
 from repro.core import transforms
 from repro.core.sampler import SamplerSpec
 from repro.distributed import codecs as wire_codecs
 from repro.engine.engine import _refresh_candidates, batched_ops
-from repro.kernels import ops
+from repro.kernels import ops, tiling
 
 
 # ---------------------------------------------------------------------------
@@ -92,15 +93,33 @@ from repro.kernels import ops
 # ---------------------------------------------------------------------------
 
 _SPARSE_PATHS: dict = {}
+_SCATTER_ROWS: dict = {}
 _FROZEN_SKETCH: dict = {}
 
 
-def register_sparse_path(name: str):
+def register_sparse_path(name: str, scatter_rows=lambda B, cfg: (B,)):
+    """``scatter_rows(B, cfg)`` gives the stream count of each scatter
+    kernel call the path makes for a (B, n) batch (``scatter_slots``)."""
     def deco(fn):
         _SPARSE_PATHS[name] = fn
+        _SCATTER_ROWS[name] = scatter_rows
         return fn
 
     return deco
+
+
+def scatter_slots(spec: SamplerSpec, B: int, n: int) -> int:
+    """Slots, padding included, that the scatter kernel sweeps when
+    ``spec``'s sparse path ingests one (B, n) batch: the ``slots`` count of
+    a ``plane.dispatch`` span.  0 where the path has no scatter."""
+    rows = _SCATTER_ROWS.get(spec.name)
+    if rows is None or n == 0:
+        return 0
+    total = 0
+    for b in rows(B, spec.cfg):
+        (_, b_pad), (_, n_pad) = tiling.scatter_tiles(b, n)
+        total += b_pad * n_pad
+    return total
 
 
 def register_frozen_sketch(name: str):
@@ -180,7 +199,8 @@ def twopass_run_update_sparse(st, keys: jnp.ndarray, values: jnp.ndarray,
     return core_sampler.TwoPassRunState(pass1=p1, pass2=p2)
 
 
-@register_sparse_path("tv")
+@register_sparse_path(
+    "tv", scatter_rows=lambda B, cfg: (B * cfg.num_samplers, B))
 @functools.partial(jax.jit, static_argnames=("p", "scheme", "interpret",
                                              "use_kernel"))
 def tv_update_sparse(st, keys: jnp.ndarray, values: jnp.ndarray, p: float,
@@ -354,6 +374,21 @@ class DataPlane:
     def _dispatch(self, state, keys, values, interpret, use_kernel):
         raise NotImplementedError
 
+    def _scatter_slots(self, B: int, n: int) -> int:
+        """Scatter-kernel slots one (B, n) ``_dispatch`` sweeps."""
+        return 0
+
+    def _stage_and_dispatch(self, state, keys, vals, interpret, use_kernel,
+                            parent=None):
+        """Hand one flushed host batch to the device (``plane.stage``) and
+        dispatch it (``plane.dispatch``, counting the scatter's slots);
+        returns the new state, still in flight."""
+        with obs.span("plane.stage", parent):
+            dkeys, dvals = jnp.asarray(keys), jnp.asarray(vals)
+        with obs.span("plane.dispatch", parent,
+                      slots=self._scatter_slots(*keys.shape)):
+            return self._dispatch(state, dkeys, dvals, interpret, use_kernel)
+
     # -- host buffer --------------------------------------------------------
     def ingest(self, keys, values):
         """Buffer one sparse signed (B, n) microbatch; dispatch when the
@@ -399,8 +434,8 @@ class DataPlane:
         trace error) leaves the microbatches intact for retry instead of
         silently dropping them."""
         keys, vals = self._concat_buffer()
-        self._state = self._dispatch(
-            self._state, jnp.asarray(keys), jnp.asarray(vals),
+        self._state = self._stage_and_dispatch(
+            self._state, keys, vals,
             self._interpret if interpret is None else interpret,
             self._use_kernel if use_kernel is None else use_kernel)
         self._clear_buffer()
@@ -464,6 +499,9 @@ class SparsePlane(DataPlane):
     def _dispatch(self, state, keys, values, interpret, use_kernel):
         return ingest_sparse(self.spec, state, keys, values,
                              interpret=interpret, use_kernel=use_kernel)
+
+    def _scatter_slots(self, B: int, n: int) -> int:
+        return scatter_slots(self.spec, B, n)
 
 
 # Async planes whose worker thread is running: shut them down at interpreter
@@ -563,7 +601,7 @@ class AsyncPlane(SparsePlane):
             if job is None:
                 self._jobs.task_done()
                 return
-            keys, vals, interpret, use_kernel = job
+            keys, vals, interpret, use_kernel, parent = job
             try:
                 with self._lock:
                     if self._error is not None:
@@ -571,8 +609,8 @@ class AsyncPlane(SparsePlane):
                         # retry drain replays failed + parked in sequence
                         self._parked.append((keys, vals))
                         continue
-                st = self._dispatch(self._state, jnp.asarray(keys),
-                                    jnp.asarray(vals), interpret, use_kernel)
+                st = self._stage_and_dispatch(self._state, keys, vals,
+                                              interpret, use_kernel, parent)
                 jax.block_until_ready(st)  # materialize: bounds in-flight
                 self._state = st
             except Exception as e:  # surfaced at the next drain/flush
@@ -645,7 +683,9 @@ class AsyncPlane(SparsePlane):
         keys, vals = self._concat_buffer()
         self._clear_buffer()
         self._cancel_timer()
-        self._jobs.put((keys, vals, interpret, use_kernel))
+        # the worker's spans take the span that submitted the batch as
+        # parent (the ``engine.ingest`` or ``engine.flush`` that flushed it)
+        self._jobs.put((keys, vals, interpret, use_kernel, obs.current()))
 
     def _flush_buffer(self, interpret=None, use_kernel=None):
         self._raise_pending_error()
@@ -822,8 +862,9 @@ class PipelinePlane(DataPlane):
     # -- partitioned dispatch ------------------------------------------------
     def _flush_buffer(self, interpret=None, use_kernel=None):
         keys, vals = self._concat_buffer()
-        for sub, (k, v) in zip(self._subplanes,
-                               partition_by_key(keys, vals, self.shards)):
+        with obs.span("plane.route"):
+            parts = partition_by_key(keys, vals, self.shards)
+        for sub, (k, v) in zip(self._subplanes, parts):
             if k.shape[1]:
                 sub.ingest(k, v)
         self._clear_buffer()

@@ -135,8 +135,8 @@ def countsketch_scatter_batched(
         B, n, seeds, transform_seeds, lengths)
 
     block_w, w_pad = tiling.fit_block(block_w, width)
-    block_n, n_pad = tiling.fit_block(block_n, n)
-    block_b, b_pad = tiling.fit_block(block_b, B, tile=tiling.SUBLANE)
+    (block_b, b_pad), (block_n, n_pad) = tiling.scatter_tiles(
+        B, n, block_b, block_n)
 
     # padded slots get key -1 => masked inside the kernel
     keys_p = jnp.pad(jnp.asarray(keys, jnp.int32),

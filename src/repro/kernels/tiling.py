@@ -49,6 +49,14 @@ def fit_block(block: int, dim: int, tile: int = LANE) -> tuple:
     return block, pad_to(dim, block)
 
 
+def scatter_tiles(B: int, n: int, block_b: int = BLOCK_B,
+                  block_n: int = BLOCK_N) -> tuple:
+    """The batched scatter kernel's (rows, columns) tiling of a (B, n)
+    batch: ``((block_b, b_pad), (block_n, n_pad))``.  ``b_pad * n_pad`` is
+    the number of slots the kernel sweeps, padding included."""
+    return (fit_block(block_b, B, tile=SUBLANE), fit_block(block_n, n))
+
+
 def packed_span(n: int, block_n: int = BLOCK_N, tile: int = LANE) -> int:
     """Element capacity of a fixed-shape host block covering ``n`` events
     with zero kernel-side re-padding: the returned span is already a whole
