@@ -156,13 +156,14 @@ def countsketch_query_batched(
 
     Returns (B, rows, k) estimates; stream b is queried against its own
     table and seed, so independent engine streams batch without sharing
-    randomness.
+    randomness.  The batch tiles as ``tiling.batch_block``: fewer than
+    ``SUBLANE`` streams need no padded tables.
     """
     B, rows, width = tables.shape
     k = keys.shape[1]
     block_k, k_pad = tiling.fit_block(tiling.BLOCK_K, max(k, 1))
     block_w, w_pad = tiling.fit_block(block_w, width)
-    block_b, b_pad = tiling.fit_block(block_b, B, tile=tiling.SUBLANE)
+    block_b, b_pad = tiling.batch_block(block_b, B)
 
     keys_p = jnp.pad(jnp.asarray(keys, jnp.int32),
                      ((0, b_pad - B), (0, k_pad - k)))

@@ -127,7 +127,9 @@ def countsketch_scatter_batched(
     negative (turnstile deletions) and duplicate keys accumulate.  Slots
     with ``keys == -1`` are padding regardless of ``lengths``.  With ``p``
     set, the bottom-k transform of ``scheme`` is fused (ppswor Exp[1] or
-    priority U(0,1] randomizer).
+    priority U(0,1] randomizer).  The batch tiles as
+    ``tiling.batch_block``: fewer than ``SUBLANE`` streams are one block of
+    exactly B rows, more pad to a whole number of ``block_b`` blocks.
     """
     B, n = keys.shape
     assert values.shape == (B, n), (keys.shape, values.shape)

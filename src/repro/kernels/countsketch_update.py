@@ -248,7 +248,9 @@ def countsketch_update_batched(
     ``base_keys[b] + [0, lengths[b])``; columns past ``lengths[b]`` are
     ignored, so ragged streams (e.g. model layers of different sizes) batch
     together.  ``seeds``/``transform_seeds`` are per-stream (B,) so streams
-    stay statistically independent unless deliberately seeded equal.
+    stay statistically independent unless deliberately seeded equal.  The
+    batch tiles as ``tiling.batch_block``: fewer than ``SUBLANE`` streams
+    are one block of exactly B rows.
     """
     B, n = values.shape
     seeds, transform_seeds, lengths = _broadcast_stream_params(
@@ -259,7 +261,7 @@ def countsketch_update_batched(
 
     block_w, w_pad = tiling.fit_block(block_w, width)
     block_n, n_pad = tiling.fit_block(block_n, n)
-    block_b, b_pad = tiling.fit_block(block_b, B, tile=tiling.SUBLANE)
+    block_b, b_pad = tiling.batch_block(block_b, B)
 
     vals = jnp.pad(values, ((0, b_pad - B), (0, n_pad - n)))
     meta = _stream_meta(b_pad, seeds, transform_seeds, lengths,

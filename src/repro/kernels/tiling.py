@@ -12,7 +12,10 @@ the selection logic is defined exactly once here and re-exported through
 
 TPU register tiling: the lane (minor) dimension of a vector register is
 128 wide and the sublane dimension 8 deep -- block dimensions that map to
-lanes pad to ``LANE``, batch/sublane dimensions to ``SUBLANE``.
+lanes pad to ``LANE``.  The batch of the batched kernels pads to
+``SUBLANE`` only from ``SUBLANE`` streams up: a smaller batch is one block
+of exactly B rows (``batch_block``), since a block dimension equal to the
+whole array dimension needs no sublane padding.
 """
 from __future__ import annotations
 
@@ -49,12 +52,21 @@ def fit_block(block: int, dim: int, tile: int = LANE) -> tuple:
     return block, pad_to(dim, block)
 
 
+def batch_block(block_b: int, B: int) -> tuple:
+    """The batched kernels' batch tiling, ``(block_b, b_pad)``: one block of
+    all B streams when ``B < SUBLANE`` (no padded streams), else
+    ``block_b`` clamped and B padded as ``fit_block`` does on sublanes."""
+    if B < SUBLANE:
+        return B, B
+    return fit_block(block_b, B, tile=SUBLANE)
+
+
 def scatter_tiles(B: int, n: int, block_b: int = BLOCK_B,
                   block_n: int = BLOCK_N) -> tuple:
     """The batched scatter kernel's (rows, columns) tiling of a (B, n)
     batch: ``((block_b, b_pad), (block_n, n_pad))``.  ``b_pad * n_pad`` is
     the number of slots the kernel sweeps, padding included."""
-    return (fit_block(block_b, B, tile=SUBLANE), fit_block(block_n, n))
+    return batch_block(block_b, B), fit_block(block_n, n)
 
 
 def packed_span(n: int, block_n: int = BLOCK_N, tile: int = LANE) -> int:

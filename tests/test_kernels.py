@@ -278,6 +278,89 @@ class TestBatchedKernelEdgeCases:
             assert int(s.keys[b, 0]) == int(want.keys[0])
 
 
+class TestBatchBlock:
+    """The batch tiling of the batched kernels (``tiling.batch_block``):
+    fewer than SUBLANE streams run as one block of exactly B rows, more pad
+    to a multiple of SUBLANE; either way each kernel equals its oracle."""
+
+    BATCHES = [1, 2, 3, 7, 8, 9, 17]
+
+    @pytest.mark.parametrize("B", BATCHES)
+    def test_batch_block(self, B):
+        from repro.kernels import tiling
+
+        block_b, b_pad = tiling.batch_block(tiling.BLOCK_B, B)
+        assert b_pad % block_b == 0 and b_pad >= B
+        if B < tiling.SUBLANE:
+            assert (block_b, b_pad) == (B, B)
+        else:
+            assert block_b == tiling.BLOCK_B and b_pad % tiling.SUBLANE == 0
+            assert b_pad - B < tiling.SUBLANE
+        assert tiling.scatter_tiles(B, 300)[0] == (block_b, b_pad)
+
+    @staticmethod
+    def _streams(B, n, seed):
+        rng = np.random.default_rng(seed)
+        keys = rng.integers(0, 50_000, (B, n)).astype(np.int32)
+        keys[:, -5:] = -1
+        vals = rng.normal(size=(B, n)).astype(np.float32)
+        seeds = jnp.asarray(rng.integers(0, 2**31 - 1, B), jnp.uint32)
+        tseeds = jnp.asarray(rng.integers(0, 2**31 - 1, B), jnp.uint32)
+        lengths = jnp.asarray(rng.integers(n // 2, n + 1, B), jnp.int32)
+        return jnp.asarray(keys), jnp.asarray(vals), seeds, tseeds, lengths
+
+    @pytest.mark.parametrize("B", BATCHES)
+    def test_scatter_matches_oracle(self, B):
+        from repro.kernels.countsketch_scatter import (
+            countsketch_scatter_batched)
+
+        keys, vals, seeds, tseeds, lengths = self._streams(B, 200, B)
+        out = countsketch_scatter_batched(
+            keys, vals, 3, 300, seeds, p=2.0, scheme="priority",
+            transform_seeds=tseeds, lengths=lengths, block_n=128,
+            block_w=128, interpret=True)
+        want = ref.countsketch_scatter_batched_ref(
+            keys, vals, 3, 300, seeds, p=2.0, transform_seeds=tseeds,
+            lengths=lengths, scheme="priority")
+        assert out.shape == (B, 3, 300)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("B", BATCHES)
+    def test_query_matches_oracle_bitwise(self, B):
+        from repro.kernels.countsketch_query import countsketch_query_batched
+
+        rng = np.random.default_rng(100 + B)
+        tables = jnp.asarray(rng.normal(size=(B, 3, 300)).astype(np.float32))
+        keys = jnp.asarray(rng.integers(0, 99_999, (B, 150)), jnp.int32)
+        seeds = jnp.asarray(rng.integers(0, 2**31 - 1, B), jnp.uint32)
+        out = countsketch_query_batched(tables, keys, seeds, block_w=128,
+                                        interpret=True)
+        want = ref.countsketch_query_batched_ref(tables, keys, seeds)
+        assert out.shape == (B, 3, 150)
+        assert np.array_equal(np.asarray(out), np.asarray(want))
+
+    @pytest.mark.parametrize("B", BATCHES)
+    def test_update_matches_oracle(self, B):
+        from repro.kernels.countsketch_update import (
+            countsketch_update_batched)
+
+        _, vals, seeds, tseeds, lengths = self._streams(B, 200, 200 + B)
+        base_keys = jnp.asarray(np.arange(B) * 1000, jnp.uint32)
+        out = countsketch_update_batched(
+            vals, 3, 300, seeds, p=1.0, transform_seeds=tseeds,
+            base_keys=base_keys, lengths=lengths, block_n=128, block_w=128,
+            interpret=True)
+        live = jnp.where(jnp.arange(200)[None, :] < lengths[:, None], vals,
+                         0.0)
+        want = jax.vmap(lambda v, b, s, t: ref.countsketch_update_ref(
+            v, b, 3, 300, s, p=1.0, transform_seed=t))(
+                live, base_keys, seeds, tseeds)
+        assert out.shape == (B, 3, 300)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+
 def worp_onepass_sample_single(st, b, k, p):
     import jax as _jax
     from repro.core import worp

@@ -136,7 +136,7 @@ def test_span_tree_of_one_ingest_on_the_sparse_plane():
     assert by["plane.stage"].parent == by["plane.dispatch"].parent == root.id
     assert by["plane.stage"].end_s <= by["plane.dispatch"].start_s
     assert by["plane.dispatch"].counts == {"slots": _slots(2, 200)}
-    assert _slots(2, 200) == 8 * 256
+    assert _slots(2, 200) == 2 * 256        # B < 8: no padded streams
 
 
 def test_span_tree_of_one_ingest_on_the_pipeline_plane():
@@ -229,7 +229,7 @@ def test_scatter_slots_count_every_scatter_call():
     onepass = SketchEngine(CFG).spec
     tv = SketchEngine(CFG, sampler="tv").spec
     perfect = SketchEngine(CFG, sampler="perfect", plane="dense").spec
-    assert planes.scatter_slots(onepass, 2, 600) == 8 * 1024
+    assert planes.scatter_slots(onepass, 2, 600) == 2 * 1024
     assert planes.scatter_slots(tv, 2, 600) == (
         _slots(2 * CFG.num_samplers, 600) + _slots(2, 600))
     assert planes.scatter_slots(perfect, 2, 600) == 0

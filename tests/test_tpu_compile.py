@@ -8,7 +8,8 @@ test asserts that a Mosaic kernel (``tpu_custom_call``) is in the compiled
 program, so that neither interpret mode nor the jnp oracle was compiled.
 
 Shapes are a deployment's: width 31 * 1024 (the paper's k x 31 for
-k = 1024), 5 rows, one 8-stream block, 4096 events per stream.
+k = 1024), 5 rows, one 8-stream block, 4096 events per stream; and one
+stream alone, as each shard of a pipeline plane runs.
 """
 import os
 
@@ -88,6 +89,35 @@ def test_query_batched_compiles(one_chip, keys):
 
     _assert_mosaic(query, one_chip, ((STREAMS, ROWS, WIDTH), jnp.float32),
                    ((STREAMS, keys), jnp.int32), ((STREAMS,), jnp.uint32))
+
+
+# one stream, as each shard of a pipeline plane: fewer than SUBLANE streams
+# run as a block of exactly B rows, which Mosaic must take unpadded.  The
+# widths are a 4-shard split of 65,536 events (scatter) and a candidate
+# refresh over 4k candidates plus that shard (query).
+@pytest.mark.parametrize("kernel", ["scatter", "query", "update"])
+def test_one_stream_kernels_compile(one_chip, kernel):
+    one = (((1,), jnp.uint32), ((1,), jnp.uint32), ((1,), jnp.int32))
+    if kernel == "scatter":
+        def fn(keys, vals, seeds, tseeds, lengths):
+            return countsketch_scatter_batched(
+                keys, vals, ROWS, WIDTH, seeds, p=2.0, scheme="priority",
+                transform_seeds=tseeds, lengths=lengths, interpret=False)
+        shapes = (((1, 19_072), jnp.int32), ((1, 19_072), jnp.float32),
+                  *one)
+    elif kernel == "query":
+        def fn(tables, qkeys, seeds):
+            return countsketch_query_batched(tables, qkeys, seeds,
+                                             interpret=False)
+        shapes = (((1, ROWS, WIDTH), jnp.float32), ((1, 23_552), jnp.int32),
+                  ((1,), jnp.uint32))
+    else:
+        def fn(vals, seeds, tseeds, lengths, base_keys):
+            return countsketch_update_batched(
+                vals, ROWS, WIDTH, seeds, p=1.0, transform_seeds=tseeds,
+                base_keys=base_keys, lengths=lengths, interpret=False)
+        shapes = (((1, EVENTS), jnp.float32), *one, ((1,), jnp.uint32))
+    _assert_mosaic(fn, one_chip, *shapes)
 
 
 def test_transform_compiles(one_chip):
