@@ -10,7 +10,7 @@ share no code with the Pallas kernels: ``kernels/ref.py``, the vmapped-jnp
 run's, with compilation counted apart; they are not a measurement.
 
     python chip_smoke.py             # one chip: ingest, sample, merge
-    python chip_smoke.py --chips 4   # only the collective merge on 4 chips
+    python chip_smoke.py --chips 4   # only the 4-device pipeline plane
 
 Exits non-zero and prints no result when JAX finds no TPU.  The last line
 of standard output is ``{"ok": true, "device": {...}}``.
@@ -298,73 +298,49 @@ def merge_phase() -> None:
                       tree1.sketch.table, tree1.sketch.seed)
 
 
-def four_chip_program(mesh):
-    """One program over ``mesh``: each device scatters its ``shard_of_keys``
-    share, a (1, windows, n) block, through the sparse kernel path; the
-    shards then collapse through the collective ``butterfly_allmerge`` and
-    ``psum_sketch``.  Returns (shard states, merged states, psum tables),
-    each stacked over the devices."""
-    import jax
-    from jax.sharding import PartitionSpec as P
-
-    from repro import engine as E
-    from repro.distributed import sharding as shd
-    from repro.engine import planes
-
-    init = E.init_batched(_shard_config(1))            # one stream per device
-
-    def worker(k, v):
-        st = init
-        for w in range(k.shape[1]):
-            st = planes.onepass_update_sparse(st, k[:, w], v[:, w], 1.0)
-        merged = shd.butterfly_allmerge(st, "shard", E.onepass_merge_batched,
-                                        axis_size=SHARDS)
-        return st, merged, shd.psum_sketch(st.sketch, "shard").table
-
-    return jax.jit(jax.shard_map(
-        worker, mesh=mesh, in_specs=(P("shard"), P("shard")),
-        out_specs=(P("shard"), P("shard"), P("shard")), check_vma=False))
-
-
-def four_chip_phase(mesh) -> None:
-    """``four_chip_program`` against the host ``tree_merge`` of the same
-    four shard states."""
+def four_chip_phase() -> None:
+    """One stream through the ``pipeline`` plane with one key-hash shard
+    resident on each of 4 devices (``devices=4``: one SPMD sparse update a
+    flush, the collective ``butterfly_allmerge`` on read) against the same
+    plane with its 4 shards on one device, folded on the host."""
     import jax
 
     from repro import engine as E
-    from repro.distributed import sharding as shd
 
-    blocks = shard_blocks(_shard_stream(), SHARDS, WINDOWS, SHARD_INSERTS)
-    keys = np.stack([k for k, _ in blocks], axis=1)   # (shards, windows, n)
-    vals = np.stack([v for _, v in blocks], axis=1)
-    run = four_chip_program(mesh)
-    with phase("4chip/scatter_and_collective_merge"):
-        out = jax.block_until_ready(run(keys, vals))
-    # to the host: a slice of a mesh-sharded array stays on the mesh, and a
-    # Mosaic kernel (the query in a merge or sample) cannot be partitioned
-    # outside shard_map; the host copies run on one device
-    local, merged, psum_tables = jax.tree_util.tree_map(np.asarray, out)
-    shards = [jax.tree_util.tree_map(lambda x, s=s: x[s:s + 1], local)
-              for s in range(SHARDS)]
-    with phase("4chip/host_tree_merge"):
-        tree = jax.block_until_ready(
-            shd.tree_merge(shards, E.onepass_merge_batched))
-    want_sample = E.onepass_sample_batched(tree, K, 1.0, use_kernel=False)
-    for d in range(SHARDS):
-        got = jax.tree_util.tree_map(lambda x, d=d: x[d:d + 1], merged)
-        check_close(f"4chip/butterfly_device{d}_vs_tree_merge",
-                    got.sketch.table, tree.sketch.table)
-        check_sample_keys(f"4chip/butterfly_device{d}_sample_vs_tree_merge",
-                          E.onepass_sample_batched(got, K, 1.0), want_sample,
-                          tree.sketch.table, tree.sketch.seed)
-        check_close(f"4chip/psum_device{d}_vs_tree_merge",
-                    psum_tables[d:d + 1], tree.sketch.table)
+    stream = _shard_stream()
+    blocks = [tuple(x[None] for x in stream.sparse_batch_at(w, 0, SHARD_INSERTS))
+              for w in range(WINDOWS)]
+    cfg = E.EngineConfig(num_streams=1, rows=ROWS, width=WIDTH,
+                         candidates=CANDIDATES, p=2.0, scheme="priority",
+                         seed=SEED)
+    engines = {}
+    for name, opts in (("devices", {"devices": SHARDS}), ("host_fold", {})):
+        eng = E.SketchEngine(cfg, plane="pipeline",
+                             flush_elems=max(k.size for k, _ in blocks),
+                             plane_opts=dict(shards=SHARDS, subplane="sparse",
+                                             **opts))
+        with phase(f"4chip/ingest_and_collapse_{name}"):
+            for k, v in blocks:
+                eng.ingest(k, v)
+            jax.block_until_ready(eng.flush().state)
+        engines[name] = eng
+    got, want = engines["devices"].state, engines["host_fold"].state
+    require(len(got.sketch.table.devices()) == 1,
+            "4chip: the collapsed state is not a one-device array")
+    check_close("4chip/collective_collapse_vs_host_fold", got.sketch.table,
+                want.sketch.table)
+    check_sample_keys("4chip/collective_collapse_sample_vs_host_fold",
+                      engines["devices"].sample(K),
+                      engines["host_fold"].sample(K),
+                      np.asarray(want.sketch.table),
+                      np.asarray(want.sketch.seed))
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run only the four-chip collective merge")
+                    help="4: run only the pipeline plane with one shard "
+                         "on each of four chips")
     args = ap.parse_args()
 
     import jax
@@ -394,9 +370,7 @@ def main() -> int:
 
     t0 = time.perf_counter()
     if args.chips == 4:
-        from repro.launch.mesh import make_mesh_auto
-
-        four_chip_phase(make_mesh_auto((SHARDS,), ("shard",)))
+        four_chip_phase()
     else:
         for p, scheme in SCHEMES:
             tenant_phase(p, scheme)

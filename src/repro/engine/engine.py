@@ -35,7 +35,8 @@ synchronous batched Pallas scatter plane, the double-buffered asynchronous
 plane (worker-thread dispatch, bit-identical drained state under the same
 flush policy), or the per-shard + collapse pipeline plane (``plane_opts=
 {"shards": S, "subplane": ...}``; merged through the sampler's composable
-merge at every read).
+merge at every read; ``"devices": S`` keeps one shard on each device and
+collapses them through the collective all-merge).
 """
 from __future__ import annotations
 
@@ -388,7 +389,9 @@ class SketchEngine:
     def state(self):
         """The settled batched sampler state.  In-flight async dispatches
         complete first; microbatches still in the HOST buffer stay pending
-        (``flush()`` applies them)."""
+        (``flush()`` applies them).  A pipeline plane with ``devices > 1``
+        returns the shards' collapsed state on the first device, as an
+        ordinary one-device array that ``sample_state`` can query."""
         return self._plane.state
 
     @state.setter

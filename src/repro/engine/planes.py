@@ -71,6 +71,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import obs
 from repro.core import countsketch, hashing, tv_sampler, worp
@@ -78,8 +79,10 @@ from repro.core import sampler as core_sampler
 from repro.core import transforms
 from repro.core.sampler import SamplerSpec
 from repro.distributed import codecs as wire_codecs
+from repro.distributed import sharding as shd
 from repro.engine.engine import _refresh_candidates, batched_ops
 from repro.kernels import ops, tiling
+from repro.launch.mesh import make_mesh_auto
 
 
 # ---------------------------------------------------------------------------
@@ -378,13 +381,17 @@ class DataPlane:
         """Scatter-kernel slots one (B, n) ``_dispatch`` sweeps."""
         return 0
 
+    def _stage(self, keys, vals):
+        """The host batch on the device the dispatch reads it from."""
+        return jnp.asarray(keys), jnp.asarray(vals)
+
     def _stage_and_dispatch(self, state, keys, vals, interpret, use_kernel,
                             parent=None):
         """Hand one flushed host batch to the device (``plane.stage``) and
         dispatch it (``plane.dispatch``, counting the scatter's slots);
         returns the new state, still in flight."""
         with obs.span("plane.stage", parent):
-            dkeys, dvals = jnp.asarray(keys), jnp.asarray(vals)
+            dkeys, dvals = self._stage(keys, vals)
         with obs.span("plane.dispatch", parent,
                       slots=self._scatter_slots(*keys.shape)):
             return self._dispatch(state, dkeys, dvals, interpret, use_kernel)
@@ -797,10 +804,57 @@ def partition_by_key(keys: np.ndarray, vals: np.ndarray,
     ``"fleet"`` plane -- identical partition, identical compacted block
     shapes, identical per-shard dispatch sequences.
     """
+    return [_compact_shard_rows(keys, vals, m)
+            for m in _shard_masks(keys, shards)]
+
+
+def _shard_masks(keys: np.ndarray, shards: int) -> list:
+    """Per shard, the (B, n) mask of the live slots routed to it."""
     shard_ids = hashing.shard_of_keys(keys, shards)
     live = keys != np.int32(-1)
-    return [_compact_shard_rows(keys, vals, (shard_ids == s) & live)
-            for s in range(shards)]
+    return [(shard_ids == s) & live for s in range(shards)]
+
+
+def stack_by_key(keys: np.ndarray, vals: np.ndarray, shards: int) -> tuple:
+    """``partition_by_key``'s blocks stacked into one (shards * B, m) block
+    of one common width: rows ``s * B .. (s + 1) * B`` are shard ``s``'s
+    block, padded with key -1 / value 0 to the widest shard's lane
+    multiple, so one compiled program serves every shard of a flush."""
+    return _compact_shard_rows(np.tile(keys, (shards, 1)),
+                               np.tile(vals, (shards, 1)),
+                               np.concatenate(_shard_masks(keys, shards)))
+
+
+@functools.lru_cache(maxsize=None)
+def _device_programs(spec: SamplerSpec, mesh, interpret, use_kernel):
+    """The device path's sharding and programs over a 1-D ``shard`` mesh,
+    where every leaf's leading axis stacks the shards' streams: one SPMD
+    sparse update (each device ingests its own shard's rows) and the
+    collapse (the collective ``butterfly_allmerge``, after which every
+    device holds the merged state).  Cached, so that every engine over
+    ``spec`` and ``mesh`` reuses one set of compiled programs."""
+    shard = PartitionSpec("shard")
+    merge = batched_ops(spec).merge
+
+    def update(st, keys, vals):
+        return ingest_sparse(spec, st, keys, vals, interpret=interpret,
+                             use_kernel=use_kernel)
+
+    def collapse(st):
+        return shd.butterfly_allmerge(st, "shard", merge,
+                                      axis_size=mesh.shape["shard"])
+
+    smap = functools.partial(jax.shard_map, mesh=mesh, out_specs=shard,
+                             check_vma=False)
+    return (NamedSharding(mesh, shard),
+            jax.jit(smap(update, in_specs=(shard, shard, shard))),
+            jax.jit(smap(collapse, in_specs=(shard,))))
+
+
+def _first_shard(x: jax.Array) -> jax.Array:
+    """The block of a shard-stacked array that the mesh's first device
+    holds, as an ordinary one-device array (no copy)."""
+    return min(x.addressable_shards, key=lambda s: s.index[0].start or 0).data
 
 
 @register_plane("pipeline")
@@ -829,6 +883,19 @@ class PipelinePlane(DataPlane):
     double-buffered worker -- N planes dispatching concurrently, collapsed
     at read time.
 
+    Device path: ``devices = shards > 1`` (``subplane="sparse"`` only) keeps
+    shard ``s`` resident on device ``s`` instead of in a sub-plane.  The
+    shard states are one pytree whose leading axis stacks the shards'
+    streams, sharded over a 1-D ``shard`` mesh; each flush is routed by
+    ``stack_by_key`` into one block of one common width, placed with one
+    ``device_put`` and ingested by one SPMD program (one compile per width,
+    not per shard).  A read collapses through the collective
+    ``butterfly_allmerge`` in one program and returns the first device's
+    merged copy as an ordinary one-device array.  The butterfly merges
+    ((0+1)+(2+3)) where the host fold merges ((0+1)+2)+3: tables differ by
+    fp32 summation order, candidate sets only by near-ties.  ``devices=1``
+    (the default) is the sub-plane path above, unchanged.
+
     ``set_state`` routes the restored state to shard 0 and resets the other
     shards to the construction-time initial state; the restored state must
     be seed-compatible with it (the merge's seed check enforces this).
@@ -836,7 +903,7 @@ class PipelinePlane(DataPlane):
 
     def __init__(self, spec, state, policy=None, interpret=None,
                  use_kernel=None, shards: int = 2, subplane: str = "sparse",
-                 codec: str = "none"):
+                 codec: str = "none", devices: int = 1):
         super().__init__(spec, state, policy=policy, interpret=interpret,
                          use_kernel=use_kernel, codec=codec)
         if shards < 1:
@@ -845,8 +912,21 @@ class PipelinePlane(DataPlane):
             raise ValueError("pipeline sub-planes cannot nest")
         self.shards = int(shards)
         self.subplane = subplane
+        self.devices = int(devices)
         self._initial = state    # merge-neutral reset state for set_state
         self._ops = batched_ops(spec)
+        self._state_bytes = sum(int(x.nbytes)
+                                for x in jax.tree_util.tree_leaves(state))
+        self._merged = None      # collapse cache, invalidated by ingest
+        if self.devices != 1:
+            self._check_devices()
+            self._subplanes = []
+            mesh = make_mesh_auto((self.devices,), ("shard",),
+                                  devices=jax.devices()[:self.devices])
+            self._sharding, self._update, self._collapse = _device_programs(
+                spec, mesh, interpret, use_kernel)
+            self.set_state(state)
+            return
         # sub-planes flush every forwarded batch: dispatch granularity is
         # decided HERE (the outer FlushPolicy / the feeder's block size).
         # They run in-process under codec "none": the wire boundary this
@@ -857,25 +937,63 @@ class PipelinePlane(DataPlane):
                        policy=FlushPolicy(max_elems=1),
                        interpret=interpret, use_kernel=use_kernel)
             for _ in range(self.shards)]
-        self._merged = None      # collapse cache, invalidated by ingest
+
+    def _check_devices(self):
+        have = len(jax.devices())
+        if self.devices < 1 or self.devices > have:
+            raise ValueError(f"pipeline plane: devices={self.devices}, but "
+                             f"JAX sees {have} device(s)")
+        if self.devices != self.shards:
+            raise ValueError(f"pipeline plane: devices={self.devices} needs "
+                             f"shards={self.devices} (one shard per device), "
+                             f"got shards={self.shards}")
+        if self.subplane != "sparse":
+            raise ValueError(f"pipeline plane: devices > 1 runs the sparse "
+                             f"update on each device; subplane="
+                             f"{self.subplane!r} has no device path")
+        if self.codec.rel_step != 0.0:
+            raise ValueError(f"pipeline plane: the collective collapse cannot "
+                             f"apply lossy codec {self.codec.name!r}")
 
     # -- partitioned dispatch ------------------------------------------------
     def _flush_buffer(self, interpret=None, use_kernel=None):
         keys, vals = self._concat_buffer()
-        with obs.span("plane.route"):
-            parts = partition_by_key(keys, vals, self.shards)
-        for sub, (k, v) in zip(self._subplanes, parts):
-            if k.shape[1]:
-                sub.ingest(k, v)
+        if self.devices > 1:
+            with obs.span("plane.route"):
+                keys, vals = stack_by_key(keys, vals, self.shards)
+            if keys.shape[1]:
+                self._state = self._stage_and_dispatch(
+                    self._state, keys, vals, interpret, use_kernel)
+        else:
+            with obs.span("plane.route"):
+                parts = partition_by_key(keys, vals, self.shards)
+            for sub, (k, v) in zip(self._subplanes, parts):
+                if k.shape[1]:
+                    sub.ingest(k, v)
         self._clear_buffer()
         self._merged = None
+
+    def _stage(self, keys, vals):
+        return jax.device_put((keys, vals), self._sharding)
+
+    def _dispatch(self, state, keys, values, interpret, use_kernel):
+        # the device programs carry the plane's own interpret/use_kernel
+        del interpret, use_kernel
+        return self._update(state, keys, values)
+
+    def _scatter_slots(self, B: int, n: int) -> int:
+        return self.shards * scatter_slots(self.spec, B // self.shards, n)
 
     def ingest_shard(self, shard: int, keys, values):
         """Feed one PRE-partitioned block straight to sub-plane ``shard``
         (every key must hash to ``shard``; -1 padding slots exempt).  This
         bypasses the outer buffer/policy -- the caller owns the dispatch
         granularity -- and is the only plane entry point that is safe to
-        call from per-shard producer threads concurrently."""
+        call from per-shard producer threads concurrently.  The device
+        path has no sub-planes and raises: feed it through ``ingest``."""
+        if self.devices > 1:
+            raise ValueError("pipeline plane: ingest_shard has no device "
+                             "path (devices > 1); use ingest")
         self._merged = None
         self._subplanes[shard].ingest(keys, values)
         return self
@@ -887,23 +1005,49 @@ class PipelinePlane(DataPlane):
 
     @property
     def state(self):
-        """The collapsed (merged-across-shards) settled state."""
+        """The collapsed (merged-across-shards) settled state, on one
+        device."""
         self._settle()
         if self._merged is None:
-            # each shard state crosses the wire ONCE (encoded + decoded)
-            # before merging; codec "none" is a copy-free identity
-            merged = self.codec.roundtrip(self._subplanes[0].state)
-            for sub in self._subplanes[1:]:
-                merged = self._ops.merge(merged,
-                                         self.codec.roundtrip(sub.state))
-            self._merged = merged
+            on_device = self.devices > 1
+            rounds = ((self.devices - 1).bit_length() if on_device
+                      else self.shards - 1)
+            with obs.span("plane.collapse", devices=self.devices,
+                          rounds=rounds, state_bytes=self._state_bytes):
+                if on_device:
+                    merged = jax.tree_util.tree_map(
+                        _first_shard, self._collapse(self._state))
+                else:
+                    # each shard state crosses the wire ONCE (encoded +
+                    # decoded) before merging; codec "none" is a copy-free
+                    # identity
+                    merged = self.codec.roundtrip(self._subplanes[0].state)
+                    for sub in self._subplanes[1:]:
+                        merged = self._ops.merge(
+                            merged, self.codec.roundtrip(sub.state))
+                self._merged = merged
         return self._merged
+
+    def collapse_hlo(self) -> str:
+        """The compiled HLO text of the device path's collapse program, or
+        "" on the sub-plane path.  A device profile names each operation
+        by its HLO instruction, so this tells a profile's collapse
+        operations apart from the other programs'.  Compiles (or fetches
+        from the compile cache) when called."""
+        if self.devices == 1:
+            return ""
+        return self._collapse.lower(self._state).compile().as_text()
 
     def set_state(self, st):
         self._settle()
-        self._subplanes[0].set_state(st)
-        for sub in self._subplanes[1:]:
-            sub.set_state(self._initial)
+        if self.devices > 1:
+            self._state = jax.device_put(jax.tree_util.tree_map(
+                lambda a, b: jnp.concatenate([a] + [b] * (self.shards - 1)),
+                st, self._initial), self._sharding)
+        else:
+            self._subplanes[0].set_state(st)
+            for sub in self._subplanes[1:]:
+                sub.set_state(self._initial)
         self._merged = None
 
     def close(self):

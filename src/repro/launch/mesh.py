@@ -12,10 +12,12 @@ import jax
 from jax.sharding import AxisType
 
 
-def make_mesh_auto(shape, axes):
+def make_mesh_auto(shape, axes, devices=None):
     """``jax.make_mesh`` with Auto axis types (bare ``make_mesh`` makes them
-    Explicit, which the sharding-in-types rules then enforce)."""
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    Explicit, which the sharding-in-types rules then enforce), over
+    ``devices`` (default: all of them)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -127,3 +127,74 @@ def test_transform_compiles(one_chip):
     n = STREAMS * EVENTS
     _assert_mosaic(transform, one_chip, ((n,), jnp.int32),
                    ((n,), jnp.float32))
+
+
+# the pipeline plane's device path on a 4-chip host: one stream's shard on
+# each chip of the described v5e:2x2, at the stream deployment's widths (a
+# 65,536-event flush routed into 4 shards of one common width)
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    from jax.experimental import topologies
+
+    from repro.launch.mesh import make_mesh_auto
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return make_mesh_auto((4,), ("shard",), devices=topo.devices)
+
+
+def _device_path(mesh):
+    from repro import engine as E
+    from repro.engine import planes
+
+    cfg = E.EngineConfig(num_streams=4, rows=ROWS, width=WIDTH,
+                         candidates=4096, p=2.0, scheme="priority")
+    sharding, update, collapse = planes._device_programs(
+        E.engine_spec(cfg), mesh, False, True)
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        jax.eval_shape(lambda: E.init_batched(cfg)))
+    return sharding, update, collapse, state
+
+
+@pytest.fixture(scope="module")
+def device_path_hlo(four_chips):
+    """The compiled HLO text of the SPMD update, each chip's block
+    (1, 19,072), and of the collapse."""
+    sharding, update, collapse, state = _device_path(four_chips)
+    keys = jax.ShapeDtypeStruct((4, 19_072), jnp.int32, sharding=sharding)
+    vals = jax.ShapeDtypeStruct((4, 19_072), jnp.float32, sharding=sharding)
+    return (update.lower(state, keys, vals).compile().as_text(),
+            collapse.lower(state).compile().as_text())
+
+
+def test_device_path_update_compiles(device_path_hlo):
+    """One SPMD program: each chip scatters and refreshes its own shard's
+    (1, 19,072) block through the Mosaic kernels inside the shard_map."""
+    hlo = device_path_hlo[0]
+    assert hlo.count("tpu_custom_call") >= 2     # scatter and query kernels
+    assert "collective-permute" not in hlo and "all-reduce" not in hlo
+
+
+def test_device_path_collapse_compiles(device_path_hlo):
+    """The collective all-merge: log2(4) = 2 rounds of collective-permute."""
+    hlo = device_path_hlo[1]
+    assert "collective-permute" in hlo
+
+
+def test_collapse_operations_are_told_apart(device_path_hlo):
+    """What the benchmark's collapse roofline relies on: the collapse
+    program runs no loop or call, so its entry instructions are every
+    operation it runs, and none of them shares its name, shape, opcode and
+    operands with an instruction of the update program."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip"))
+    import collective_work as cw
+
+    update, collapse = (cw.program_keys(h) for h in device_path_hlo)
+    opcodes = {k[2] for k in collapse}
+    assert {"collective-permute-start", "collective-permute-done"} <= opcodes
+    assert not opcodes & {"while", "conditional", "call"}
+    assert update and not update & collapse
