@@ -1,20 +1,28 @@
 """Pallas TPU kernel: CountSketch query (per-row estimates for a key batch).
 
 Estimating k keys needs table[r, bucket_r(key)] for every row r -- a gather on
-GPU.  TPU adaptation: the gather becomes a one-hot matmul over width blocks:
+GPU.  TPU adaptation: the gather becomes one-hot contractions on the MXU.
+The final median-over-rows is O(R*K) and runs outside the kernel (ops layer).
+
+Single-stream variant (``countsketch_query``): a one-hot matmul over width
+blocks, each (K,) accumulator tile resident in VMEM across the width sweep:
 
     est_r  =  sum_j  onehot_j(keys) @ table[r, j*WB:(j+1)*WB]^T
-
-Each (K,) accumulator tile stays in VMEM across the width sweep; the batched
-variant tiles the keys too, so its one-hot block fits the chip's VMEM.
-The final median-over-rows is O(R*K) and runs outside the kernel (ops layer).
 
 Batched variant (``countsketch_query_batched``): the grid grows a leading
 batch dimension so the B streams of a ``SketchEngine`` -- each with its own
 table and hash seed -- are estimated by ONE ``pallas_call`` instead of a
-Python loop of B dispatches.  Per-stream seeds ride in a (B, 128) meta table
-and the one-hot gather becomes a batched contraction on the MXU.  This is
-the engine's batched estimate / sample / candidate-refresh query plane.
+Python loop of B dispatches.  Per-stream seeds ride in a (B, 128) meta
+table.  It reads each bucket once, with no width sweep: a table row is
+viewed as (H, 128) and ``bucket = 128 * hi + lo``, so
+
+    part_r  =  table_r^T @ onehot(hi)      (128, H) @ (H, K)  on the MXU
+    est_r   =  sum_lanes where(lane == lo, part_r, 0)          on the VPU
+
+Each output sums one nonzero term, so it equals the table cell bitwise.
+The grid is (batch blocks, key blocks), and a stream block's table stays in
+VMEM across its key blocks.  This is the engine's batched estimate / sample
+/ candidate-refresh query plane.
 """
 from __future__ import annotations
 
@@ -109,46 +117,43 @@ def countsketch_estimate(table, keys, seed, interpret: bool = True):
 
 _META_SEED = 0
 _META_COLS = 128
+_LANE_BITS = tiling.LANE.bit_length() - 1
 
 
 def _batched_kernel(meta_ref, keys_ref, table_ref, out_ref, *, rows: int,
-                    width: int, block_w: int):
-    # grid = (batch_blocks, key_blocks, width_blocks): each (stream-block,
-    # key-tile) accumulator revisits across the width sweep.
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
+                    width: int):
+    # grid = (batch_blocks, key_blocks): the (B, rows, H, 128) table block
+    # depends on the batch block only, so it stays resident across the keys.
     seed = meta_ref[:, _META_SEED:_META_SEED + 1].astype(jnp.uint32)  # (B,1)
     keys = keys_ref[...].astype(jnp.uint32)                           # (B,K)
-    col0 = j * block_w
-    # transposed one-hot (B, WB, K): the table row is the (B, 1, WB) lhs, so
-    # every operand keeps its lane dimension and Mosaic needs no gather
-    cols = jax.lax.broadcasted_iota(jnp.int32, (1, block_w, 1), 1) + col0
+    # bucket = LANE * hi + lo: hi picks a sublane row of the (H, LANE) table
+    # row, lo a lane of that row
+    his = jax.lax.broadcasted_iota(jnp.int32, (1, table_ref.shape[2], 1), 1)
+    los = jax.lax.broadcasted_iota(jnp.int32, (1, tiling.LANE, 1), 1)
 
     ests = []
     for r in range(rows):
         salt = hashing.row_salt(seed, jnp.uint32(r))          # (B, 1)
         bucket = hashing.bucket_hash(keys, salt, width)       # (B, K)
         sign = hashing.sign_hash(keys, salt)                  # (B, K)
-        onehot = (bucket[:, None, :] == cols).astype(jnp.float32)
-        trow = table_ref[:, r:r + 1, :].astype(jnp.float32)   # (B, 1, WB)
-        # batched contraction: B streams on the MXU -> (B, 1, K)
-        part = onehot_dot(trow, onehot, (((2,), (1,)), ((0,), (0,))))
-        ests.append(part * sign[:, None, :])
-    out_ref[...] += jnp.concatenate(ests, axis=1)             # (B, rows, K)
+        hi = jnp.right_shift(bucket, _LANE_BITS)[:, None, :]  # (B, 1, K)
+        lo = jnp.bitwise_and(bucket, tiling.LANE - 1)[:, None, :]
+        onehot = (hi == his).astype(jnp.bfloat16)             # (B, H, K)
+        trow = table_ref[:, r].astype(jnp.float32)            # (B, H, LANE)
+        # row hi of every key's table row, lanes on sublanes: (B, LANE, K)
+        part = onehot_dot(trow, onehot, (((1,), (1,)), ((0,), (0,))))
+        # lane lo of it: one nonzero term summed with zeros, so exact
+        est = jnp.sum(jnp.where(lo == los, part, 0.0), axis=1,
+                      keepdims=True)                           # (B, 1, K)
+        ests.append(est * sign[:, None, :])
+    out_ref[...] = jnp.concatenate(ests, axis=1)              # (B, rows, K)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_w", "block_b", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def countsketch_query_batched(
     tables: jnp.ndarray,   # (B, rows, width) per-stream tables
     keys: jnp.ndarray,     # (B, k) per-stream key batches
     seeds: jnp.ndarray,    # (B,) per-stream hash seeds
-    block_w: int = tiling.BLOCK_W,
     block_b: int = tiling.BLOCK_B,
     interpret: bool = True,
 ) -> jnp.ndarray:
@@ -157,34 +162,43 @@ def countsketch_query_batched(
     Returns (B, rows, k) estimates; stream b is queried against its own
     table and seed, so independent engine streams batch without sharing
     randomness.  The batch tiles as ``tiling.batch_block``: fewer than
-    ``SUBLANE`` streams need no padded tables.
+    ``SUBLANE`` streams need no padded tables.  The width pads with zero
+    columns to whole (SUBLANE, LANE) tiles, never addressed since every
+    bucket is below ``width``, and each table row is read as (H, LANE).
     """
     B, rows, width = tables.shape
     k = keys.shape[1]
-    block_k, k_pad = tiling.fit_block(tiling.BLOCK_K, max(k, 1))
-    block_w, w_pad = tiling.fit_block(block_w, width)
     block_b, b_pad = tiling.batch_block(block_b, B)
+    w_pad = _pad_to(width, tiling.SUBLANE * tiling.LANE)
+    h = w_pad // tiling.LANE
+    block_k, k_pad = tiling.fit_block(tiling.query_block_k(block_b, h),
+                                      max(k, 1))
 
     keys_p = jnp.pad(jnp.asarray(keys, jnp.int32),
                      ((0, b_pad - B), (0, k_pad - k)))
     tables_p = jnp.pad(tables, ((0, b_pad - B), (0, 0), (0, w_pad - width)))
+    tables_p = tables_p.reshape(b_pad, rows, h, tiling.LANE)
     seeds = jnp.broadcast_to(jnp.asarray(seeds, jnp.uint32), (B,))
     meta = jnp.zeros((b_pad, _META_COLS), jnp.int32)
     meta = meta.at[:B, _META_SEED].set(seeds.astype(jnp.int32))
 
-    grid = (b_pad // block_b, k_pad // block_k, w_pad // block_w)
+    grid = (b_pad // block_b, k_pad // block_k)
     out = pl.pallas_call(
-        functools.partial(_batched_kernel, rows=rows, width=width,
-                          block_w=block_w),
+        functools.partial(_batched_kernel, rows=rows, width=width),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b, _META_COLS), lambda b, q, j: (b, 0)),
-            pl.BlockSpec((block_b, block_k), lambda b, q, j: (b, q)),
-            pl.BlockSpec((block_b, rows, block_w), lambda b, q, j: (b, 0, j)),
+            pl.BlockSpec((block_b, _META_COLS), lambda b, q: (b, 0)),
+            pl.BlockSpec((block_b, block_k), lambda b, q: (b, q)),
+            pl.BlockSpec((block_b, rows, h, tiling.LANE),
+                         lambda b, q: (b, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, rows, block_k),
-                               lambda b, q, j: (b, 0, q)),
+                               lambda b, q: (b, 0, q)),
         out_shape=jax.ShapeDtypeStruct((b_pad, rows, k_pad), jnp.float32),
+        # the table block, double-buffered, over the default 16 MiB of
+        # scoped VMEM that holds the tiles query_block_k sizes
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * block_b * rows * w_pad * 4 + (16 << 20)),
         interpret=interpret,
         name="worp_countsketch_query_batched",
     )(meta, keys_p, tables_p)

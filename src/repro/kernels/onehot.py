@@ -7,8 +7,9 @@ bf16 pieces whose f32 sum is the value exactly (8 + 8 + 8 of its 24
 mantissa bits), and each piece is contracted on its own with f32
 accumulation.  Three MXU passes instead of the six of ``Precision.HIGHEST``,
 whose temporaries do not fit VMEM at the default tiles.  Where each output
-sums one nonzero term (the query's gather) the result is the value bitwise;
-where it sums many (scatter, update) it is an fp32 sum.
+sums one nonzero term (the query's one-hot over width blocks or over a
+table row's sublane rows) the result is the value bitwise; where it sums
+many (scatter, update) it is an fp32 sum.
 """
 from __future__ import annotations
 

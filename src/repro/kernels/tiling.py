@@ -27,9 +27,13 @@ SUBLANE = 8
 BLOCK_N = 512
 BLOCK_W = 1024
 BLOCK_B = 8
-# key tile of the batched query: a one-hot over all keys ran a v5e out of
-# VMEM already at 1024 keys ((8, 1024, 1024) f32, 32 MiB).
-BLOCK_K = 512
+# key tiles of the batched query (``query_block_k``): each grid step holds a
+# (block_b, h, block_k) one-hot over the table's sublane rows and three
+# (block_b, LANE, block_k) f32 reads.  Capping the one-hot keeps them near
+# 7 MiB of a v5e's VMEM beside the resident table block, and capping the
+# keys keeps a one-stream kernel's unrolled body, and its compile, small.
+BLOCK_K = 1024
+QUERY_ONEHOT = 1 << 20
 
 # single-stream kernels have no batch dimension competing for VMEM, so they
 # afford larger tiles.
@@ -59,6 +63,14 @@ def batch_block(block_b: int, B: int) -> tuple:
     if B < SUBLANE:
         return B, B
     return fit_block(block_b, B, tile=SUBLANE)
+
+
+def query_block_k(block_b: int, h: int) -> int:
+    """Key tile of the batched query for ``block_b`` streams whose table
+    rows are read as (h, LANE): at most ``BLOCK_K`` keys a stream and
+    ``QUERY_ONEHOT`` one-hot elements a grid step, in whole lanes."""
+    k = min(BLOCK_K, QUERY_ONEHOT // (block_b * h))
+    return max(LANE, k // LANE * LANE)
 
 
 def scatter_tiles(B: int, n: int, block_b: int = BLOCK_B,

@@ -137,6 +137,22 @@ class TestKernelCoreEquivalence:
                                    atol=2e-5)
 
 
+def _query_keys(rng, B, k, seeds, width):
+    """(B, k) query keys: random, a few ``_EMPTY`` (-1) padding keys, and
+    first in stream 0 a key whose row-0 bucket is ``width - 1``, so the last
+    addressed sublane row of the query's (H, 128) table view is read."""
+    from repro.core import hashing
+
+    keys = rng.integers(0, 99_999, (B, k)).astype(np.int32)
+    keys[:, 1:4] = -1
+    cand = np.arange(100_000, 100_000 + 64 * width, dtype=np.int32)
+    salt = hashing.row_salt(jnp.uint32(seeds[0]), jnp.uint32(0))
+    bucket = np.asarray(hashing.bucket_hash(jnp.asarray(cand), salt, width))
+    assert (bucket == width - 1).any()
+    keys[0, 0] = cand[np.argmax(bucket == width - 1)]
+    return jnp.asarray(keys)
+
+
 class TestBatchedKernelEdgeCases:
     """Grid/padding edge cases for the BATCHED query + scatter kernels:
     widths and batch sizes that do NOT divide the block sizes, all-padding
@@ -155,17 +171,21 @@ class TestBatchedKernelEdgeCases:
         tseeds = jnp.asarray(rng.integers(0, 2**31 - 1, B), jnp.uint32)
         return keys, vals, seeds, tseeds
 
-    @pytest.mark.parametrize("B,width", RAGGED)
+    # the query's own cases beside RAGGED: B of 3, 8 and 9 streams at
+    # widths that are no multiple of 128, of 1024, or the deployment's
+    QUERY_RAGGED = RAGGED + [(3, 777), (8, 1000), (9, 31 * 1024)]
+
+    @pytest.mark.parametrize("B,width", QUERY_RAGGED)
     def test_query_nonmultiple_blocks(self, B, width):
         from repro.kernels.countsketch_query import countsketch_query_batched
 
         rng = np.random.default_rng(B)
         tables = jnp.asarray(
             rng.normal(size=(B, 3, width)).astype(np.float32))
-        keys = jnp.asarray(rng.integers(0, 99_999, (B, 37)), jnp.int32)
         seeds = jnp.asarray(rng.integers(0, 2**31 - 1, B), jnp.uint32)
-        out = countsketch_query_batched(tables, keys, seeds, block_w=128,
-                                        block_b=8, interpret=True)
+        keys = _query_keys(rng, B, 37, seeds, width)
+        out = countsketch_query_batched(tables, keys, seeds, block_b=8,
+                                        interpret=True)
         want = ref.countsketch_query_batched_ref(tables, keys, seeds)
         assert np.array_equal(np.asarray(out), np.asarray(want))
 
@@ -218,19 +238,23 @@ class TestBatchedKernelEdgeCases:
                                    rtol=2e-5, atol=2e-5)
 
     def test_query_multiple_key_blocks(self):
-        """More keys than one key tile: the batched query's key grid axis."""
+        """More keys than one key tile: the batched query's key grid axis,
+        for a one-block batch and for two blocks of 8 streams."""
         from repro.kernels import tiling
         from repro.kernels.countsketch_query import countsketch_query_batched
 
         rng = np.random.default_rng(4)
-        k = 2 * tiling.BLOCK_K + 77
-        tables = jnp.asarray(rng.normal(size=(3, 2, 300)).astype(np.float32))
-        keys = jnp.asarray(rng.integers(0, 99_999, (3, k)), jnp.int32)
-        seeds = jnp.asarray(rng.integers(0, 2**31 - 1, 3), jnp.uint32)
-        out = countsketch_query_batched(tables, keys, seeds, block_w=128,
-                                        interpret=True)
-        want = ref.countsketch_query_batched_ref(tables, keys, seeds)
-        assert np.array_equal(np.asarray(out), np.asarray(want))
+        for B in (3, 9):
+            block_b, _ = tiling.batch_block(tiling.BLOCK_B, B)
+            k = 2 * tiling.query_block_k(block_b, 8) + 77
+            tables = jnp.asarray(
+                rng.normal(size=(B, 2, 300)).astype(np.float32))
+            seeds = jnp.asarray(rng.integers(0, 2**31 - 1, B), jnp.uint32)
+            keys = _query_keys(rng, B, k, seeds, 300)
+            out = countsketch_query_batched(tables, keys, seeds,
+                                            interpret=True)
+            want = ref.countsketch_query_batched_ref(tables, keys, seeds)
+            assert np.array_equal(np.asarray(out), np.asarray(want))
 
     def test_query_single_key(self):
         """k == 1 sample queries (the smallest possible key batch)."""
@@ -238,10 +262,9 @@ class TestBatchedKernelEdgeCases:
 
         rng = np.random.default_rng(3)
         tables = jnp.asarray(rng.normal(size=(5, 3, 777)).astype(np.float32))
-        keys = jnp.asarray(rng.integers(0, 99_999, (5, 1)), jnp.int32)
         seeds = jnp.asarray(rng.integers(0, 2**31 - 1, 5), jnp.uint32)
-        out = countsketch_query_batched(tables, keys, seeds, block_w=256,
-                                        interpret=True)
+        keys = _query_keys(rng, 5, 1, seeds, 777)
+        out = countsketch_query_batched(tables, keys, seeds, interpret=True)
         want = ref.countsketch_query_batched_ref(tables, keys, seeds)
         assert out.shape == (5, 3, 1)
         assert np.array_equal(np.asarray(out), np.asarray(want))
@@ -298,6 +321,20 @@ class TestBatchBlock:
             assert b_pad - B < tiling.SUBLANE
         assert tiling.scatter_tiles(B, 300)[0] == (block_b, b_pad)
 
+    # (block_b, h, key tile): 8 or 1 streams at the deployment's h = 248,
+    # and at k = 4096's h = 992, where the one-hot cap binds
+    @pytest.mark.parametrize("block_b,h,want", [
+        (8, 248, 512), (1, 248, 1024), (3, 8, 1024), (8, 992, 128),
+        (1, 992, 1024)])
+    def test_query_block_k(self, block_b, h, want):
+        from repro.kernels import tiling
+
+        got = tiling.query_block_k(block_b, h)
+        assert got == want and got % tiling.LANE == 0
+        assert got <= tiling.BLOCK_K
+        assert block_b * h * got <= max(tiling.QUERY_ONEHOT,
+                                        block_b * h * tiling.LANE)
+
     @staticmethod
     def _streams(B, n, seed):
         rng = np.random.default_rng(seed)
@@ -332,10 +369,9 @@ class TestBatchBlock:
 
         rng = np.random.default_rng(100 + B)
         tables = jnp.asarray(rng.normal(size=(B, 3, 300)).astype(np.float32))
-        keys = jnp.asarray(rng.integers(0, 99_999, (B, 150)), jnp.int32)
         seeds = jnp.asarray(rng.integers(0, 2**31 - 1, B), jnp.uint32)
-        out = countsketch_query_batched(tables, keys, seeds, block_w=128,
-                                        interpret=True)
+        keys = _query_keys(rng, B, 150, seeds, 300)
+        out = countsketch_query_batched(tables, keys, seeds, interpret=True)
         want = ref.countsketch_query_batched_ref(tables, keys, seeds)
         assert out.shape == (B, 3, 150)
         assert np.array_equal(np.asarray(out), np.asarray(want))

@@ -80,14 +80,23 @@ def test_update_batched_compiles(one_chip):
 
 
 # 1024: a k = 1024 sample's keys; 8192: a candidate refresh (4k candidates
-# plus a 4096-event block)
-@pytest.mark.parametrize("keys", [1024, 8192])
-def test_query_batched_compiles(one_chip, keys):
+# plus a 4096-event block); 5120: the tenants deployment's refresh (4k
+# candidates plus a 1024-event block), also at a width that is no multiple
+# of 1024, and at k = 4096's width, whose table block outgrows the default
+# scoped VMEM
+@pytest.mark.parametrize("keys,width", [
+    pytest.param(1024, WIDTH, id="1024"),
+    pytest.param(8192, WIDTH, id="8192"),
+    pytest.param(5120, WIDTH, id="5120"),
+    pytest.param(5120, 30_000, id="5120-w30000"),
+    pytest.param(4096, 31 * 4096, id="4096-w126976"),
+])
+def test_query_batched_compiles(one_chip, keys, width):
     def query(tables, qkeys, seeds):
         return countsketch_query_batched(tables, qkeys, seeds,
                                          interpret=False)
 
-    _assert_mosaic(query, one_chip, ((STREAMS, ROWS, WIDTH), jnp.float32),
+    _assert_mosaic(query, one_chip, ((STREAMS, ROWS, width), jnp.float32),
                    ((STREAMS, keys), jnp.int32), ((STREAMS,), jnp.uint32))
 
 
